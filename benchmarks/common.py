@@ -88,9 +88,12 @@ def run_subprocess_py(code: str, devices: int = 8, timeout: int = 1200,
                       with_bench_path: bool = False) -> str:
     """Run a snippet under N host devices; returns stdout.
 
-    ``with_bench_path`` adds the repo root to PYTHONPATH so the snippet
-    can import the ``benchmarks`` package itself."""
+    The child is pinned to the CPU backend: its mesh is N virtual host
+    devices by design, and a parent that has imported jax may hold the
+    accelerator. ``with_bench_path`` adds the repo root to PYTHONPATH so
+    the snippet can import the ``benchmarks`` package itself."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     path = [os.path.join(ROOT, "src")] + ([ROOT] if with_bench_path else [])
     env["PYTHONPATH"] = os.pathsep.join(path)

@@ -22,7 +22,6 @@ HLO_SNIPPET = """
 import numpy as np, jax, jax.numpy as jnp, json
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import migration
-from repro.sharding import shard_map
 from repro.analysis.hlo import parse_collectives
 e, T, d, H, block = 8, 64, 128, 512, 16
 mesh = Mesh(np.array(jax.devices()).reshape(e), ("model",))
@@ -35,7 +34,7 @@ kw = dict(axis="model", mig_src=jnp.array(0, jnp.int32),
 out = {}
 for name, fn in [("broadcast_reduce", migration.migrated_pair_matmul),
                  ("scatter_gather", migration.scatter_gather_pair_matmul)]:
-    f = shard_map(lambda x, a, b: fn(x, a, b, **kw), mesh=mesh,
+    f = jax.shard_map(lambda x, a, b: fn(x, a, b, **kw), mesh=mesh,
         in_specs=(P(), P(None, "model"), P("model", None)),
         out_specs=P(), check_vma=False)
     txt = jax.jit(f).lower(x, w1, w2).compile().as_text()

@@ -64,6 +64,8 @@ def main() -> None:
     args = ap.parse_args()
     if args.dry_run:
         os.environ["REPRO_BENCH_DRY"] = "1"
+    from repro.launch._bootstrap import enable_compile_cache
+    enable_compile_cache()
 
     only = set(args.only.split(",")) if args.only else None
     if args.suite:

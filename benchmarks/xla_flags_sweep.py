@@ -36,7 +36,6 @@ import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.layers.tp_linear import chunked_psum
-from repro.sharding import shard_map
 
 devs = jax.devices()
 mesh = jax.sharding.Mesh(np.array(devs), ("x",))
@@ -52,9 +51,9 @@ def step(x_, w_):
     y = y + jax.nn.silu(y)                      # compute to overlap with
     return chunked_psum(y, "x", {chunks})
 
-f = jax.jit(shard_map(step, mesh=mesh,
+f = jax.jit(jax.shard_map(step, mesh=mesh,
                       in_specs=(P(None, "x"), P("x", None)),
-                      out_specs=P()))
+                      out_specs=P(), check_vma=False))
 r = f(x, w); r.block_until_ready()
 ts = []
 for _ in range({iters}):

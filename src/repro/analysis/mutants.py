@@ -120,14 +120,14 @@ def _m_vmem_blowout(env: reg.CaseEnv) -> List[reg.Artifact]:
 
 def _m_f64_leak(env: reg.CaseEnv) -> List[reg.Artifact]:
     """R5: an accidental float64 promotion inside the step."""
-    from jax.experimental import enable_x64
+    import jax
 
     def fn(x):
         return x.astype("float64") * 2.0
 
     case = reg.TraceCase(step="mutant", name="f64_leak", fn=fn,
                          args=(_sds((8,)),))
-    with enable_x64():
+    with jax.enable_x64(True):
         return [engine.trace_artifact(case, env)]
 
 

@@ -14,7 +14,7 @@ Estimate per pallas_call::
         +     Σ scratch_bytes                   # resident, single copy
 
 Scalar-prefetch operands live in SMEM and are excluded. The grid_mapping
-introspection is version-sensitive (jax 0.4.x); failures degrade to an
+introspection follows jax 0.9's pallas_call params; failures degrade to an
 "unpriced" report rather than a crash — the rule only fires on kernels
 it could actually price.
 """
@@ -75,8 +75,11 @@ def _block_bytes(grid_mapping) -> Tuple[int, List[str]]:
     total = 0
     detail = []
     for i, bm in enumerate(grid_mapping.block_mappings):
+        # entries are ints or pallas Blocked(block_size=...) markers;
+        # squeezed dims count as 1
+        dims = (getattr(d, "block_size", d) for d in bm.block_shape)
         shape = tuple(int(d) if isinstance(d, (int, np.integer)) else 1
-                      for d in bm.block_shape)
+                      for d in dims)
         sds = getattr(bm, "array_shape_dtype", None)
         nbytes = int(np.prod(shape or (1,))) * (
             _dtype_bytes(sds.dtype) if sds is not None else 4)

@@ -359,6 +359,15 @@ def list_configs() -> list:
     return sorted(_REGISTRY)
 
 
+def resolve_model(model) -> ModelConfig:
+    """What an entry point runs: a :class:`ModelConfig` is used as given
+    (published widths, depth cuts); a registered name gives its
+    :func:`smoke_variant`, the size the CPU tests and benchmarks run."""
+    if isinstance(model, ModelConfig):
+        return model
+    return smoke_variant(get_config(model))
+
+
 def smoke_variant(cfg: ModelConfig) -> ModelConfig:
     """Reduced same-family variant: 2 layers, d_model<=512, <=4 experts."""
     d = min(cfg.d_model, 256)

@@ -164,6 +164,13 @@ class ControlPlane:
                     f"real mesh scale (sim_ranks={self.sim_ranks} != "
                     f"tp={tp})")
         self.geometry = geo
+        if not geo:
+            # the kernel path plans at a block the chip can compile (a
+            # ragged geometry is counted in wc.block_size blocks and keeps
+            # that grid); self.wc is what the controller plans against
+            wc = dataclasses.replace(wc, block_size=scopes_lib.plan_block_size(
+                model_cfg, wc.block_size, tp, wc.use_kernel))
+            self.wc = wc
 
         # -- plan skeleton (real mesh scale) -------------------------------
         static = None

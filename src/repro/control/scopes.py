@@ -24,6 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import ModelConfig
 from repro.core.workload import PlanStatic, adapt_block_size
+from repro.kernels.ops import LANES
 
 SDS = jax.ShapeDtypeStruct
 
@@ -193,3 +194,18 @@ def control_block_size(cfg: ModelConfig, static: PlanStatic) -> int:
     if b and loc // b >= 2:
         return b
     return 0
+
+
+def plan_block_size(cfg: ModelConfig, block_size: int, tp: int,
+                    use_kernel: bool) -> int:
+    """Pruning-block preference a plan is built at.
+
+    The Pallas pruned kernels compile only at a 128-multiple block
+    (kernels/ops.py ``LANES``), so with ``use_kernel`` the plan takes
+    ``LANES`` wherever the per-rank FFN width holds two or more such
+    blocks. Elsewhere (smoke widths, which run the kernels in interpret
+    mode) the configured ``block_size`` stands."""
+    loc = _controlled_dff(cfg) // max(tp, 1)
+    if use_kernel and loc % LANES == 0 and loc // LANES >= 2:
+        return LANES
+    return block_size

@@ -35,6 +35,11 @@ INTERPRET = None
 _TRUTHY = ("1", "true", "yes", "on")
 _FALSY = ("0", "false", "no", "off")
 
+# TPU vector lane width: the pruning block is the last dim of every
+# kernel tile that streams a pruned block, so a compiled (non-interpret)
+# kernel needs it to be a multiple of this
+LANES = 128
+
 # cached env + backend resolution: every kernel wrapper consults
 # interpret_mode() per call, and jax.default_backend() is not free —
 # resolve once, invalidate explicitly via reset_interpret_cache()
@@ -106,6 +111,13 @@ def _validate(K: int, w_rows: int, keep_idx: jax.Array, block: int,
             f"{what}: contraction dim K={K} is not a multiple of the "
             f"pruning block size {block} (K would be silently truncated); "
             "choose a block via repro.core.workload.adapt_block_size")
+    if block % LANES and not interpret_mode():
+        raise ValueError(
+            f"{what}: pruning block {block} is not a multiple of the "
+            f"{LANES}-lane vector width, so Mosaic cannot tile it on the "
+            "TPU; plan the controlled scope at a 128-multiple block "
+            "(repro.control.scopes.plan_block_size) or run in interpret "
+            "mode")
     nb = K // block
     if keep_idx.ndim != 1:
         raise ValueError(

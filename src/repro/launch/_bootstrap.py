@@ -35,12 +35,39 @@ def argv_str(flag: str, default: str = "") -> str:
 
 
 def ensure_host_devices(n: int) -> None:
-    """Request n XLA host devices if jax has not been initialized yet
+    """Request n XLA host devices if jax has not been initialized yet and
+    the run is pinned to the CPU (``JAX_PLATFORMS=cpu``). On any other
+    platform the flag stays off, so a run whose accelerator fails to start
+    errors out instead of silently continuing on virtual CPU devices
     (library users set XLA_FLAGS themselves)."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        return
     if n > 1 and "jax" not in sys.modules:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={n}")
+
+
+#: JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is
+#: unset: a fixed path inside the checkout (the path is part of the
+#: cache key, so a moving directory would never hit). Listed in .gitignore.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory. JAX reads ``JAX_COMPILATION_CACHE_DIR`` itself,
+    so when it is set this changes nothing; otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`. Called from ``main``s only, never at
+    import time or from tests."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 # ---------------------------------------------------------------------------

@@ -57,7 +57,7 @@ import collections
 import dataclasses
 import time
 import warnings
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Union
 
 import numpy as np
 
@@ -65,12 +65,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import store as ckpt_store
-from repro.config import ShapeConfig, get_config, smoke_variant
+from repro.config import ModelConfig, ShapeConfig, resolve_model
 from repro.control import ControlConfig, ControlPlane
 from repro.core import geometry as geom_lib
 from repro.core import hetero as hetero_lib
 from repro.core import paging as paging_lib
 from repro.launch import steps as steps_lib
+from repro.launch._bootstrap import enable_compile_cache
 from repro.launch.mesh import make_small_mesh
 from repro.models import get_api
 from repro.sharding import ragged_local_width, use_mesh
@@ -181,7 +182,8 @@ class ServeControlConfig(ControlConfig):
 class ServeEngine:
     """Continuous-batching decode engine over a fixed slot set."""
 
-    def __init__(self, arch: str, num_slots: int = 4, max_len: int = 64, *,
+    def __init__(self, arch: Union[str, ModelConfig], num_slots: int = 4,
+                 max_len: int = 64, *,
                  tp: int = 1, ckpt_dir: Optional[str] = None, seed: int = 0,
                  control: Optional[ControlConfig] = None,
                  param_dtype: str = "float32",
@@ -200,8 +202,12 @@ class ServeEngine:
         step (decode slots still advance one token), so a long prompt
         no longer serializes the batch. ``kv_int8`` stores the GQA K/V
         pool in int8 with per-row f32 scales (half the pool HBM; not
-        bit-exact, oracle attention path only)."""
-        self.cfg = smoke_variant(get_config(arch))
+        bit-exact, oracle attention path only).
+
+        ``arch`` is a registered name (served at its smoke variant) or a
+        :class:`ModelConfig`, served as given."""
+        self.cfg = resolve_model(arch)
+        arch = self.cfg.name if isinstance(arch, ModelConfig) else arch
         cfg_canonical = self.cfg
         self.api = get_api(self.cfg)
         if not self.api.has_decode or self.cfg.encdec is not None:
@@ -387,18 +393,26 @@ class ServeEngine:
 
         # ---- params + slot cache ----------------------------------------
         # params (and checkpoints) are CANONICAL; a ragged geometry
-        # expands them into the zero-padded layout at load time
-        params, _ = self.api.init(jax.random.PRNGKey(seed), cfg_canonical,
-                                  dtype)
+        # expands them into the zero-padded layout at load time. Fresh
+        # params are made on the device under jit, straight into their
+        # shardings, so a full-width model never has a second copy.
+        def init_params():
+            return self.api.init(jax.random.PRNGKey(seed), cfg_canonical,
+                                 dtype)[0]
+
+        params = None
         if ckpt_dir:
             # race-tolerant latest-committed load: a warm spare may be
             # promoted while a trainer is mid-save in the same directory
-            _, loaded = ckpt_store.load_latest_params(ckpt_dir, params)
-            if loaded is not None:
-                params = loaded
+            _, params = ckpt_store.load_latest_params(
+                ckpt_dir, jax.eval_shape(init_params))
         if self.geometry is not None:
-            params = geom_lib.expand_ffn_params(params, self.geometry)
-        self.params = jax.device_put(params, in_sh[0])
+            params = geom_lib.expand_ffn_params(
+                jax.jit(init_params)() if params is None else params,
+                self.geometry)
+        self.params = (jax.jit(init_params, out_shardings=in_sh[0])()
+                       if params is None
+                       else jax.device_put(params, in_sh[0]))
         self.cache = jax.device_put(
             self.api.init_cache(self.cfg, num_slots, max_len, dtype,
                                 paging=self.paging)
@@ -939,13 +953,17 @@ def latency_percentiles(completions: List[Completion],
 class FixedBatchEngine:
     """Holds params + a jitted single-token step; serves fixed batches."""
 
-    def __init__(self, arch: str, batch: int, max_len: int,
-                 ckpt_dir: Optional[str] = None, seed: int = 0):
-        self.cfg = smoke_variant(get_config(arch))
+    def __init__(self, arch: Union[str, ModelConfig], batch: int,
+                 max_len: int, ckpt_dir: Optional[str] = None,
+                 seed: int = 0):
+        self.cfg = resolve_model(arch)
         self.api = get_api(self.cfg)
         self.batch = batch
         self.max_len = max_len
-        params, _ = self.api.init(jax.random.PRNGKey(seed), self.cfg)
+        # made under jit, as ServeEngine makes its params: the two engines
+        # must hold bit-identical weights for token-exact comparisons
+        params = jax.jit(lambda: self.api.init(jax.random.PRNGKey(seed),
+                                               self.cfg)[0])()
         if ckpt_dir:
             last = ckpt_store.latest_step(ckpt_dir)
             if last is not None:
@@ -1047,6 +1065,7 @@ def main():
                     help="int8-quantize the paged K/V pools (per-row "
                          "scales; oracle attention path only)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     control = ControlConfig(
         mode=args.control, hetero_kind=args.hetero, chi=args.chi,

@@ -78,8 +78,8 @@ def build_rank_time_gather(mesh: Mesh, axis: str = "model"):
     def local_gather(x):                      # x: [1] this rank's clock
         return jax.lax.all_gather(x, axis, tiled=True)
 
-    gathered = sh.shard_map(local_gather, mesh=mesh, in_specs=P(axis),
-                            out_specs=P())
+    gathered = jax.shard_map(local_gather, mesh=mesh, in_specs=P(axis),
+                             out_specs=P(), check_vma=False)
     return jax.jit(gathered,
                    in_shardings=NamedSharding(mesh, P(axis)),
                    out_shardings=_replicated(mesh)) if e > 1 else \

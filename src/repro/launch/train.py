@@ -27,7 +27,9 @@ from __future__ import annotations
 
 # CLI nicety: when invoked as a script with --tp/--dp > 1, request that many
 # host devices BEFORE jax initializes (shared jax-free helper).
-from repro.launch._bootstrap import argv_int as _argv_int, ensure_host_devices
+from repro.launch._bootstrap import (argv_int as _argv_int,
+                                     enable_compile_cache,
+                                     ensure_host_devices)
 
 ensure_host_devices(_argv_int("--tp") * _argv_int("--dp"))
 
@@ -35,7 +37,7 @@ import argparse
 import dataclasses
 import json
 import os
-from typing import Dict, Optional
+from typing import Dict, Optional, Union
 
 import numpy as np
 
@@ -43,8 +45,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import store as ckpt_store
-from repro.config import (ShapeConfig, TrainConfig, get_config,
-                          smoke_variant)
+from repro.config import (ModelConfig, ShapeConfig, TrainConfig,
+                          resolve_model)
 from repro.control import ControlConfig, ControlPlane
 from repro.control.plane import make_schedule
 from repro.core import geometry as geom_lib
@@ -112,8 +114,9 @@ def _resolve_geometry(spec: Optional[str], cfg, tp: int, *, hetero_kind: str,
     return None if geo.is_equal else geo
 
 
-def run_training(arch: str, *, steps: int = 50, tp: int = 1, dp: int = 1,
-                 control_mode: str = "off", hetero_kind: str = "none",
+def run_training(arch: Union[str, ModelConfig], *, steps: int = 50,
+                 tp: int = 1, dp: int = 1, control_mode: str = "off",
+                 hetero_kind: str = "none",
                  chi: float = 2.0, lr: float = 3e-3, batch: int = 8,
                  seq: int = 64, seed: int = 0, log_every: int = 10,
                  ckpt_dir: Optional[str] = None, resume: bool = False,
@@ -131,8 +134,12 @@ def run_training(arch: str, *, steps: int = 50, tp: int = 1, dp: int = 1,
                  measure_noise: float = 0.0,
                  ckpt_every: int = 50,
                  geometry: Optional[str] = None) -> Dict:
-    """Returns a summary dict (loss/acc curves, modeled step times)."""
-    cfg = smoke_variant(get_config(arch))
+    """Returns a summary dict (loss/acc curves, modeled step times).
+
+    ``arch`` is a registered name (trained at its smoke variant) or a
+    :class:`ModelConfig`, trained as given."""
+    cfg = resolve_model(arch)
+    arch = cfg.name if isinstance(arch, ModelConfig) else arch
     cfg_canonical = cfg
     geo = _resolve_geometry(geometry, cfg, tp, hetero_kind=hetero_kind,
                             chi=chi, period=hetero_period, seed=seed,
@@ -407,7 +414,7 @@ def run_training(arch: str, *, steps: int = 50, tp: int = 1, dp: int = 1,
             if controller is not None and (it + 1) % 10 == 0:
                 stats = scope_stats()
                 if stats:
-                    controller.observe_weights(stats, control_cfg.block_size)
+                    controller.observe_weights(stats, plane.wc.block_size)
 
             if eval_every and (it + 1) % eval_every == 0 and cfg.num_classes:
                 from repro.data.pipeline import eval_accuracy
@@ -501,6 +508,7 @@ def main():
                          "into this many async-overlappable psums")
     ap.add_argument("--out", default=None, help="write history JSON here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     hist = run_training(
         args.arch, steps=args.steps, tp=args.tp, dp=args.dp,

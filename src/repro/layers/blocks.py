@@ -14,6 +14,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig
 from repro.layers import attention as attn_lib
@@ -21,7 +22,7 @@ from repro.layers import moe as moe_lib
 from repro.layers import rglru as rglru_lib
 from repro.layers import ssm as ssm_lib
 from repro.layers.tp_linear import ControlContext, controlled_ffn, controlled_proj
-from repro.sharding import shard
+from repro.sharding import current_mesh, shard
 
 Params = Dict[str, Any]
 
@@ -113,6 +114,34 @@ def _paged_write_ids(pages: jax.Array, cur_pos: jax.Array,
     return page, cur_pos % page_size
 
 
+def _fused_decode(kernel: Callable, mesh, n_heads: int, args: Tuple,
+                  head_axes: Tuple[Optional[int], ...]) -> jax.Array:
+    """Run a fused decode-attention kernel, per TP shard on a mesh.
+
+    GSPMD cannot partition a Mosaic kernel, so on a multi-device mesh the
+    call runs inside ``shard_map``: ``args[i]`` is split over "model" on
+    its ``head_axes[i]`` axis (None = replicated) when the mesh divides
+    ``n_heads`` — the kv-head count, so each rank keeps whole GQA groups —
+    and every rank runs all heads otherwise. The output's head axis is 1.
+    """
+    mesh = mesh or current_mesh()
+    if mesh is None or mesh.size == 1:
+        return kernel(*args)
+    n = dict(mesh.shape).get("model", 0)
+    h = "model" if n and n_heads % n == 0 else None
+
+    def spec(a, ax):
+        parts = [None] * a.ndim
+        if ax is not None:
+            parts[ax] = h
+        return P(*parts)
+
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=tuple(spec(a, ax) for a, ax in zip(args, head_axes)),
+        out_specs=P(None, h), check_vma=False)(*args)
+
+
 def apply_attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
                     ctx: Optional[ControlContext], positions: jax.Array,
                     causal: bool = True, window: int = 0,
@@ -196,8 +225,11 @@ def apply_attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
                     "kv_int8 paging has no fused kernel path — run with "
                     "fused_attention off (oracle dequant)")
             from repro.kernels import ops as _kops
-            o = _kops.fused_paged_decode_attention(
-                q, kc, vc, pages=pages, cur_pos=cur_pos, window=window)
+            o = _fused_decode(
+                lambda q_, k_, v_, pg, cp:
+                    _kops.fused_paged_decode_attention(
+                        q_, k_, v_, pages=pg, cur_pos=cp, window=window),
+                mesh, KV, (q, kc, vc, pages, cur_pos), (1, 1, 1, None, None))
         else:
             o = attn_lib.paged_decode_attention(
                 q, kc, vc, pages=pages, cur_pos=cur_pos, window=window,
@@ -220,8 +252,10 @@ def apply_attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
             # online softmax over the ragged cache, no [B, H, S] scores
             # in HBM; interpret-mode fallback keeps CPU containers green
             from repro.kernels import ops as _kops
-            o = _kops.fused_decode_attention(q, kc, vc, cur_pos=cur_pos,
-                                             window=window)
+            o = _fused_decode(
+                lambda q_, k_, v_, cp: _kops.fused_decode_attention(
+                    q_, k_, v_, cur_pos=cp, window=window),
+                mesh, KV, (q, kc, vc, cur_pos), (1, 1, 1, None))
         else:
             o = attn_lib.decode_attention(q, kc, vc, cur_pos=cur_pos,
                                           window=window)
@@ -312,18 +346,25 @@ def _apply_mla(p, x, cfg, *, ctx, positions, cache, cur_pos, pages=None):
         q_abs = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)
         if pages is not None and cfg.fused_decode_attn:
             from repro.kernels import ops as _kops
-            o_lat = _kops.fused_paged_mla_decode_attention(
-                q_abs, q_rope[:, 0], lc, rc, pages=pages,
-                cur_pos=cur_pos, head_dim_for_scale=dn + dr)
+            o_lat = _fused_decode(
+                lambda qa, qr, l_, r_, pg, cp:
+                    _kops.fused_paged_mla_decode_attention(
+                        qa, qr, l_, r_, pages=pg, cur_pos=cp,
+                        head_dim_for_scale=dn + dr),
+                mesh, H, (q_abs, q_rope[:, 0], lc, rc, pages, cur_pos),
+                (1, 1, None, None, None, None))
         elif pages is not None:
             o_lat = attn_lib.paged_mla_decode_attention(
                 q_abs, q_rope[:, 0], lc, rc, pages=pages,
                 cur_pos=cur_pos, head_dim_for_scale=dn + dr)
         elif cfg.fused_decode_attn:
             from repro.kernels import ops as _kops
-            o_lat = _kops.fused_mla_decode_attention(
-                q_abs, q_rope[:, 0], lc, rc, cur_pos=cur_pos,
-                head_dim_for_scale=dn + dr)                # [B,H,R]
+            o_lat = _fused_decode(
+                lambda qa, qr, l_, r_, cp: _kops.fused_mla_decode_attention(
+                    qa, qr, l_, r_, cur_pos=cp,
+                    head_dim_for_scale=dn + dr),
+                mesh, H, (q_abs, q_rope[:, 0], lc, rc, cur_pos),
+                (1, 1, None, None, None))                   # [B,H,R]
         else:
             o_lat = attn_lib.mla_decode_attention(
                 q_abs, q_rope[:, 0], lc, rc, cur_pos=cur_pos,

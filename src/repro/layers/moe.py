@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.config import MoEConfig
-from repro.sharding import shard, shard_map
+from repro.sharding import shard
 
 
 def router_topk(x: jax.Array, w_router: jax.Array, cfg: MoEConfig
@@ -168,7 +168,7 @@ def _moe_tp_local(x: jax.Array, params: dict, cfg: MoEConfig, act_fn, mesh
     if gated:
         in_specs.append(P(None, None, "model"))
         args.append(params["w_gate"])
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh, in_specs=tuple(in_specs),
         out_specs=(P(dp, None, None), P()), check_vma=False)(*args)
     return y, aux

@@ -40,7 +40,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import resizing
 from repro.core.migration import fused_migration_delta
 from repro.core.workload import PlanStatic, keep_blocks_for_bucket
-from repro.sharding import filter_spec_for_mesh, shard, shard_map
+from repro.sharding import filter_spec_for_mesh, shard
 
 
 @dataclasses.dataclass
@@ -154,7 +154,7 @@ def controlled_proj(x: jax.Array, w: jax.Array, ctx: Optional[ControlContext],
                 x_, w_, pri_, bucket_[0], buckets=st.buckets,
                 block=blk, use_kernel=ctx.use_kernel)
 
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
+        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                          out_specs=out_spec, check_vma=False)(
             x, w, ctx.bucket_by_rank, pri)
 
@@ -171,7 +171,7 @@ def controlled_proj(x: jax.Array, w: jax.Array, ctx: Optional[ControlContext],
             block=blk, use_kernel=ctx.use_kernel)
         return chunked_psum(y, axis, ctx.psum_chunks)
 
-    return shard_map(body_row, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(body_row, mesh=mesh, in_specs=in_specs,
                      out_specs=out_spec, check_vma=False)(
         x, w, ctx.bucket_by_rank, pri)
 
@@ -364,5 +364,5 @@ def controlled_ffn(x: jax.Array, w_up: jax.Array, w_down: jax.Array,
 
     args = (x, w_up, w_down) + ((w_gate,) if w_gate is not None else ()) + (
         ctx.bucket_by_rank, pri, ctx.mig_src)
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                      out_specs=out_spec, check_vma=False)(*args)

@@ -59,22 +59,6 @@ def logical_to_spec(axes: Sequence[Optional[str]], rules=None) -> P:
     return P(*parts)
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable ``shard_map``.
-
-    Newer jax exposes ``jax.shard_map`` (with ``check_vma``); the releases
-    this repo also supports only ship ``jax.experimental.shard_map``
-    (whose equivalent flag is ``check_rep``). All shard_map call sites in
-    the repo go through this wrapper.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 def shard(x, axes: Sequence[Optional[str]], mesh: Optional[Mesh] = None, rules=None):
     """Apply a logical-axes sharding constraint inside jit.
 

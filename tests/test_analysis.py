@@ -172,9 +172,8 @@ def test_r3_grouped_psum_jaxpr_counting():
     def trace(fn):
         mesh = jax.sharding.Mesh(
             __import__("numpy").array(jax.devices()[:1]), ("i",))
-        from repro.sharding import shard_map
         from jax.sharding import PartitionSpec as P
-        return jax.make_jaxpr(shard_map(
+        return jax.make_jaxpr(jax.shard_map(
             fn, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
             check_vma=False))(sds, sds)
 
@@ -247,8 +246,7 @@ def test_r5_fires_on_f64_in_hlo_and_respects_allowance():
 
 def test_r5_fires_on_f64_jaxpr():
     import jax
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         jx = jax.make_jaxpr(lambda x: x.astype("float64") * 2)(
             jax.ShapeDtypeStruct((4,), "float32"))
     assert _rules_fired([Artifact(case=_case(), jaxpr=jx)], "R5")

@@ -1,8 +1,5 @@
 """Property-based tests for the controller's closed-form math (Eq. 2/3)
 and the pinned Φ1 cost-function behavior.
-
-Runs under real `hypothesis` when installed (CI) and under the seeded
-deterministic fallback otherwise (tests/_hypothesis_fallback.py).
 """
 import numpy as np
 import pytest

@@ -12,7 +12,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def run_py(code: str, devices: int = 8, timeout: int = 420) -> str:
+    # CPU virtual-device meshes by design: the child never competes for
+    # an accelerator the parent may hold
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -144,7 +147,6 @@ class TestMigrationPrimitives:
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import migration
-from repro.sharding import shard_map
 e, T, d, H, block = 8, 16, 32, 128, 4
 mesh = Mesh(np.array(jax.devices()).reshape(e), ("model",))
 rng = np.random.default_rng(0)
@@ -155,10 +157,10 @@ act = jax.nn.silu
 ids = jnp.array([0, 2, 3], jnp.int32)
 kw = dict(axis="model", mig_src=jnp.array(4, jnp.int32),
           mig_block_ids=ids, block=block, act_fn=act)
-f1 = shard_map(lambda x,a,b: migration.migrated_pair_matmul(x,a,b,**kw),
+f1 = jax.shard_map(lambda x,a,b: migration.migrated_pair_matmul(x,a,b,**kw),
     mesh=mesh, in_specs=(P(), P(None,"model"), P("model",None)),
     out_specs=P(), check_vma=False)
-f2 = shard_map(lambda x,a,b: migration.scatter_gather_pair_matmul(x,a,b,**kw),
+f2 = jax.shard_map(lambda x,a,b: migration.scatter_gather_pair_matmul(x,a,b,**kw),
     mesh=mesh, in_specs=(P(), P(None,"model"), P("model",None)),
     out_specs=P(), check_vma=False)
 y1, y2 = f1(x, w1, w2), f2(x, w1, w2)
