@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran, averaged over
+the chips."""
+from chipbench.metrics._common import idle_share
+
+
+def read(run):
+    return idle_share(run)
