@@ -44,12 +44,14 @@ import tempfile
 import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
-sys.path.insert(0, os.path.join(ROOT, "src"))
+sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 import numpy as np  # noqa: E402
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+
+from chipbench.compilelog import CompileLog  # noqa: E402
 
 # yi-6b serving: 4 slots, 8 requests of a 96-token prompt and 32 new
 # tokens, a 16-token page pool and 8-token prefill chunks
@@ -74,35 +76,6 @@ TRAIN_LAYERS = 8
 TOL = {"paged_decode_attention": 2e-2, "block_pruned_matmul": 1e-2,
        "block_pruned_matmul_grad": 1e-2, "fused_pruned_ffn": 2e-2,
        "fused_pruned_ffn_grad": 4e-2}
-
-_COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-                   "/jax/core/compile/jaxpr_to_mlir_module_duration",
-                   "/jax/core/compile/backend_compile_duration")
-
-
-class CompileLog:
-    """Compile work seen through jax.monitoring: seconds spent tracing,
-    lowering and compiling, how often that happened, and persistent-cache
-    hits and misses."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.events = 0
-        self.cache_hits = 0
-        self.cache_misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, name, secs, **_):
-        if name in _COMPILE_EVENTS:
-            self.seconds += secs
-            self.events += 1
-
-    def _event(self, name, **_):
-        if name == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-        elif name == "/jax/compilation_cache/cache_misses":
-            self.cache_misses += 1
 
 
 class LoweredModules:

@@ -27,7 +27,9 @@ Responsibilities:
   mitigation space), picks the executable for the projected signature,
   and assembles the dynamic plan arrays.
 * **telemetry** — measurement capture, online estimation, and replayable
-  trace output, identical for both drivers.
+  trace output, identical for both drivers. ``decide``, ``dispatch``
+  and ``capture`` are profiler spans (``repro.control.*``,
+  DESIGN_TELEMETRY.md §7).
 * **checkpoint** — :meth:`state_arrays` / :meth:`state_meta` /
   :meth:`load_state` round-trip the controller's T_avg bookkeeping,
   priority statistics, estimator window and every host RNG stream, so a
@@ -51,7 +53,7 @@ from repro.core.controller import SemiController, work_fraction
 from repro.core.workload import PlanCompileCache, PlanStatic, WorkloadPlan
 from repro.telemetry import (EstimatorConfig, RankTimer, StragglerEstimator,
                              TraceWriter, capture_sample, measurement_rng,
-                             schedule_from_trace)
+                             schedule_from_trace, span)
 
 
 def make_schedule(kind: str, num_ranks: int, *, chi: float = 2.0,
@@ -291,7 +293,8 @@ class ControlPlane:
 
     def decide(self, times: np.ndarray):
         """Run the controller (Alg. 2) on per-rank times."""
-        return self.controller.plan(times)
+        with span("repro.control.decide"):
+            return self.controller.plan(times)
 
     def dispatch(self, plan: WorkloadPlan):
         """Executable + dynamic plan arrays for a plan, on the real mesh.
@@ -304,7 +307,16 @@ class ControlPlane:
         Returns ``(step_fn, plan_arrays, projected)`` — ``projected`` is
         the :class:`ProjectedPlan` that actually EXECUTES (drivers report
         it, not the sim-scale plan, as the migration ground truth).
+        The span's ``new_signature`` is 1 when the compile cache built
+        a new executable for this plan.
         """
+        with span("repro.control.dispatch") as sp:
+            built = self.cache.compile_count
+            out = self._dispatch(plan)
+            sp.count(new_signature=int(self.cache.compile_count > built))
+        return out
+
+    def _dispatch(self, plan: WorkloadPlan):
         # under a ragged geometry the clamp is against the SMALLEST rank's
         # real blocks — any rank can be retargeted as a source dynamically
         real_ffn_nb = (min(self.geometry) if self.geometry
@@ -367,14 +379,17 @@ class ControlPlane:
         is rank-aligned with the real mesh (sim group == real tp)."""
         if self.estimator is None and self.writer is None:
             return None
-        sample = capture_sample(
-            self.it_model, chis, work_frac, step=step, plan=plan, wall=wall,
-            rng=self.measure_rng, noise=self.measure_noise,
-            timer=self.timer if self.sim_ranks == self.tp else None)
-        if self.estimator is not None:
-            self.estimator.observe(sample)
-        if self.writer is not None:
-            self.writer.append(sample)
+        with span("repro.control.capture") as sp:
+            gathers = self.timer.gather_count
+            sample = capture_sample(
+                self.it_model, chis, work_frac, step=step, plan=plan,
+                wall=wall, rng=self.measure_rng, noise=self.measure_noise,
+                timer=self.timer if self.sim_ranks == self.tp else None)
+            if self.estimator is not None:
+                self.estimator.observe(sample)
+            if self.writer is not None:
+                self.writer.append(sample)
+            sp.count(gathered=int(self.timer.gather_count > gathers))
         return sample
 
     def close(self) -> None:

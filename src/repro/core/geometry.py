@@ -174,10 +174,12 @@ def geometry_from_chi(chis: Sequence[float], total_blocks: int, block: int,
     ideal = share * total_blocks
     sizes = np.maximum(np.floor(ideal).astype(np.int64), min_blocks)
     # largest-remainder: hand out the residual blocks to the largest
-    # fractional parts (ties broken by rank id — deterministic)
+    # fractional parts (ties broken by rank id — deterministic), taken
+    # against the clamped counts so a rank raised to min_blocks is not
+    # also handed a residual block ahead of a faster rank
     rem = int(total_blocks - sizes.sum())
     if rem > 0:
-        frac = ideal - np.floor(ideal)
+        frac = ideal - sizes
         order = np.lexsort((np.arange(tp), -frac))
         for k in range(rem):
             sizes[order[k % tp]] += 1

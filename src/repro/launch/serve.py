@@ -75,6 +75,7 @@ from repro.launch._bootstrap import enable_compile_cache
 from repro.launch.mesh import make_small_mesh
 from repro.models import get_api
 from repro.sharding import ragged_local_width, use_mesh
+from repro.telemetry import span
 
 
 # ---------------------------------------------------------------------------
@@ -423,6 +424,7 @@ class ServeEngine:
         # ---- host-side state ---------------------------------------------
         self.queue: collections.deque = collections.deque()
         self._eligible_clock: Dict[int, float] = {}   # req.uid -> TTFT start
+        self._submitted: Dict[int, float] = {}   # req.uid -> host submit time
         self.slots: List[Optional[_Slot]] = [None] * num_slots
         self.free: List[int] = list(range(num_slots))[::-1]
         self.step_count = 0
@@ -447,6 +449,7 @@ class ServeEngine:
         if self.max_queue is not None and len(self.queue) >= self.max_queue:
             return False
         self.queue.append(req)
+        self._submitted[req.uid] = time.perf_counter()
         # time-to-first-token starts when the request becomes ELIGIBLE
         # (arrival), not when a slot frees up — queue wait is part of
         # TTFT. Keyed by req.uid: keying by id(req) handed a NEW request
@@ -492,6 +495,7 @@ class ServeEngine:
         prefill), but zeroing keeps recycling uniformly exact."""
         clear = np.zeros((self.num_slots,), np.float32)
         admitted = []
+        now = time.perf_counter()
         # mark queue members that just became eligible (TTFT clock start)
         for req in self.queue:
             if req.arrival_step <= self.step_count:
@@ -510,6 +514,11 @@ class ServeEngine:
                 t_mark=t0, t_elig=t0, latencies=[])
             clear[slot] = 1.0
             admitted.append(req.uid)
+            # the queue wait on the host clock, for the profiler
+            wait_ms = 1e3 * (now - self._submitted.get(req.uid, now))
+            with span("repro.serve.request_admitted", uid=int(req.uid),
+                      queue_wait_ms=wait_ms):
+                pass
         return admitted, clear
 
     # -- page-pool bookkeeping (paged engine only) ---------------------------
@@ -578,35 +587,54 @@ class ServeEngine:
         prefill and decode interleave freely across slots with no
         retrace. On the paged engine, page lists are grown to cover this
         step's writes first, preempting the newest-admitted slot when the
-        pool runs dry."""
-        admitted, clear = self._admit()
-        preempted = self._ensure_pages() if self.alloc is not None else []
+        pool runs dry.
+
+        The step and its phases are profiler spans (``repro.serve.*``,
+        DESIGN_TELEMETRY.md §7); the counters of ``repro.serve.step``
+        are set once the step has run."""
+        with span("repro.serve.step", step=int(self.step_count)) as sp:
+            return self._run_step(sp)
+
+    def _run_step(self, sp) -> Dict:
+        with span("repro.serve.admit") as sp_admit:
+            admitted, clear = self._admit()
+            preempted = (self._ensure_pages() if self.alloc is not None
+                         else [])
+            sp_admit.count(admitted=len(admitted), preempted=len(preempted))
 
         C = self.prefill_chunk
         B = self.num_slots
-        tokens_cb = np.zeros((C, B), np.int32)
-        pos_cb = np.full((C, B), paging_lib.INVALID_POS, np.int32)
-        valid_cb = np.zeros((C, B), np.float32)
-        feed = np.zeros((B,), np.int32)       # positions fed per slot
-        last_pos = np.zeros((B,), np.int32)   # highest position fed
-        active = np.zeros((B,), np.float32)
-        for i, s in enumerate(self.slots):
-            if s is None:
-                continue
-            active[i] = 1.0
-            P = len(s.req.prompt)
-            if s.pos < P:                     # teacher-forced prefill chunk
-                n = min(C, P - s.pos)
-                tokens_cb[:n, i] = np.asarray(s.req.prompt[s.pos:s.pos + n],
-                                              np.int32)
-                pos_cb[:n, i] = np.arange(s.pos, s.pos + n)
-            else:                             # one greedy decode token
-                n = 1
-                tokens_cb[0, i] = s.next_token
-                pos_cb[0, i] = s.pos
-            valid_cb[:n, i] = 1.0
-            feed[i] = n
-            last_pos[i] = s.pos + n - 1
+        with span("repro.serve.feed") as sp_feed:
+            tokens_cb = np.zeros((C, B), np.int32)
+            pos_cb = np.full((C, B), paging_lib.INVALID_POS, np.int32)
+            valid_cb = np.zeros((C, B), np.float32)
+            feed = np.zeros((B,), np.int32)       # positions fed per slot
+            last_pos = np.zeros((B,), np.int32)   # highest position fed
+            active = np.zeros((B,), np.float32)
+            for i, s in enumerate(self.slots):
+                if s is None:
+                    continue
+                active[i] = 1.0
+                P = len(s.req.prompt)
+                if s.pos < P:                 # teacher-forced prefill chunk
+                    n = min(C, P - s.pos)
+                    tokens_cb[:n, i] = np.asarray(
+                        s.req.prompt[s.pos:s.pos + n], np.int32)
+                    pos_cb[:n, i] = np.arange(s.pos, s.pos + n)
+                else:                         # one greedy decode token
+                    n = 1
+                    tokens_cb[0, i] = s.next_token
+                    pos_cb[0, i] = s.pos
+                valid_cb[:n, i] = 1.0
+                feed[i] = n
+                last_pos[i] = s.pos + n - 1
+            lanes = int(feed.sum())
+            sp_feed.count(lanes=lanes)
+            with use_mesh(self.mesh):
+                fed = (jnp.asarray(tokens_cb), jnp.asarray(pos_cb),
+                       jnp.asarray(valid_cb), jnp.asarray(clear))
+                if self.alloc is not None:
+                    fed = fed + (jnp.asarray(self.alloc.table()),)
 
         # chunked prefill feeds MORE than one token per occupied slot;
         # price the extra substep work as extra workload fraction so the
@@ -643,16 +671,13 @@ class ServeEngine:
 
         self.plane.timer.start()
         with use_mesh(self.mesh):
-            args = (self.params, self.cache, jnp.asarray(tokens_cb),
-                    jnp.asarray(pos_cb), jnp.asarray(valid_cb),
-                    jnp.asarray(clear))
-            if self.alloc is not None:
-                args = args + (jnp.asarray(self.alloc.table()),)
+            args = (self.params, self.cache) + fed
             if plan_arrays is not None:
                 args = args + (plan_arrays,)
             tok_ids, self.cache = step_fn(*args)
         wall = self.plane.timer.stop(tok_ids)
-        nxt = np.asarray(jax.device_get(tok_ids))      # [C, num_slots]
+        with span("repro.serve.fetch"):
+            nxt = np.asarray(jax.device_get(tok_ids))  # [C, num_slots]
         overhead = 0.0
         if self.schedule is None:
             latency = dense_latency = wall       # no simulation: real time
@@ -673,42 +698,48 @@ class ServeEngine:
 
         # -- harvest per slot ---------------------------------------------
         completed = []
-        for i, s in enumerate(self.slots):
-            if s is None or feed[i] == 0:
-                continue
-            n = int(feed[i])
-            prev = s.pos
-            s.pos = prev + n
-            P = len(s.req.prompt)
-            if prev < P and s.pos < P:
-                continue                         # still mid-prefill
-            # the last fed position's logits carry the next token (chunk
-            # end == prompt end for the prefill→decode handoff)
-            tok = int(nxt[n - 1, i])
-            emitted = False
-            if len(s.generated) < s.req.max_new_tokens:
-                s.generated.append(tok)
-                s.latencies.append(self.clock - s.t_mark)
-                s.t_mark = self.clock
-                emitted = True
-            done = (len(s.generated) >= s.req.max_new_tokens
-                    or (emitted and s.req.eos_id is not None
-                        and tok == s.req.eos_id))
-            if done or s.pos >= self.max_len:
-                self.completions.append(Completion(
-                    uid=s.req.uid, prompt=s.req.prompt,
-                    tokens=np.asarray(s.generated, np.int32),
-                    admitted_step=s.admitted_step,
-                    finished_step=self.step_count, slot=i,
-                    token_latencies=list(s.latencies)))
-                completed.append(s.req.uid)
-                self._eligible_clock.pop(s.req.uid, None)
-                self.slots[i] = None
-                self.free.append(i)
-                if self.alloc is not None:
-                    self.alloc.free_slot(i)
-            else:
-                s.next_token = tok
+        with span("repro.serve.harvest") as sp_harvest:
+            for i, s in enumerate(self.slots):
+                if s is None or feed[i] == 0:
+                    continue
+                n = int(feed[i])
+                prev = s.pos
+                s.pos = prev + n
+                P = len(s.req.prompt)
+                if prev < P and s.pos < P:
+                    continue                         # still mid-prefill
+                # the last fed position's logits carry the next token (chunk
+                # end == prompt end for the prefill→decode handoff)
+                tok = int(nxt[n - 1, i])
+                emitted = False
+                if len(s.generated) < s.req.max_new_tokens:
+                    s.generated.append(tok)
+                    s.latencies.append(self.clock - s.t_mark)
+                    s.t_mark = self.clock
+                    emitted = True
+                done = (len(s.generated) >= s.req.max_new_tokens
+                        or (emitted and s.req.eos_id is not None
+                            and tok == s.req.eos_id))
+                if done or s.pos >= self.max_len:
+                    self.completions.append(Completion(
+                        uid=s.req.uid, prompt=s.req.prompt,
+                        tokens=np.asarray(s.generated, np.int32),
+                        admitted_step=s.admitted_step,
+                        finished_step=self.step_count, slot=i,
+                        token_latencies=list(s.latencies)))
+                    completed.append(s.req.uid)
+                    self._eligible_clock.pop(s.req.uid, None)
+                    self._submitted.pop(s.req.uid, None)
+                    with span("repro.serve.request_done",
+                              uid=int(s.req.uid), tokens=len(s.generated)):
+                        pass
+                    self.slots[i] = None
+                    self.free.append(i)
+                    if self.alloc is not None:
+                        self.alloc.free_slot(i)
+                else:
+                    s.next_token = tok
+            sp_harvest.count(completed=len(completed))
 
         report = {"step": self.step_count, "latency_s": latency,
                   "dense_latency_s": dense_latency, "wall_s": wall,
@@ -728,6 +759,11 @@ class ServeEngine:
                 / (self.num_slots * self.max_len))
             report["attn_bound_s"] = self.overhead.attn_s(
                 last_pos, fused=True, active=active)
+        sp.count(active=int(active.sum()), lanes=lanes,
+                 admitted=len(admitted), completed=len(completed),
+                 preempted=len(preempted), queued=len(self.queue))
+        if self.alloc is not None:
+            sp.count(pages_free=self.alloc.free_pages)
         if plan_report is not None:
             report["stragglers"] = list(plan_report.stragglers)
             report["max_bucket"] = int(plan_report.bucket_by_rank.max())
@@ -823,6 +859,7 @@ class ServeEngine:
         self.queue.clear()
         for req in out:
             self._eligible_clock.pop(req.uid, None)
+            self._submitted.pop(req.uid, None)
         return out
 
     def active_requests(self) -> List[Request]:

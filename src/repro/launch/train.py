@@ -59,6 +59,7 @@ from repro.launch.mesh import make_small_mesh
 from repro.models import get_api
 from repro.optim import adamw
 from repro.sharding import ragged_local_width, use_mesh
+from repro.telemetry import span
 
 
 @dataclasses.dataclass
@@ -322,120 +323,146 @@ def run_training(arch: Union[str, ModelConfig], *, steps: int = 50,
         def scope_stats():
             """Mean-over-layers weight matrices per controlled scope:
             ffn -> w_down [d_ff, d]; qkv -> wq [d, H*hd]; attn_out ->
-            wo [H*hd, d] (contraction dim first in every case)."""
+            wo [H*hd, d] (contraction dim first in every case).
+
+            Returns the statistics and the bytes copied to the host; each
+            copy is the profiler span ``repro.control.weight_stats.fetch``
+            so the caller's span keeps the mean as its own time."""
             st = params["stack"] if "stack" in params else params.get("decoder", {})
             scan = st.get("scan") if isinstance(st, dict) else None
             if scan is None:
-                return {}
+                return {}, 0
             out = {}
+            copied = 0
+
+            def mean_of(a):
+                nonlocal copied
+                with span("repro.control.weight_stats.fetch",
+                          bytes=int(a.nbytes)):
+                    host = np.asarray(jax.device_get(a))
+                copied += int(a.nbytes)
+                return host.mean(axis=0)
+
             for grp in (scan if isinstance(scan, tuple) else (scan,)):
                 if not isinstance(grp, dict):
                     continue
                 if "ffn" in grp and "ffn" in scopes and "ffn" not in out:
-                    out["ffn"] = np.asarray(
-                        jax.device_get(grp["ffn"]["w_down"])).mean(axis=0)
+                    out["ffn"] = mean_of(grp["ffn"]["w_down"])
                 if "attn" in grp and isinstance(grp["attn"], dict):
                     if "qkv" in scopes and "wq" in grp["attn"] and "qkv" not in out:
-                        out["qkv"] = np.asarray(
-                            jax.device_get(grp["attn"]["wq"])).mean(axis=0)
+                        out["qkv"] = mean_of(grp["attn"]["wq"])
                     if "attn_out" in scopes and "wo" in grp["attn"]                             and "attn_out" not in out:
-                        out["attn_out"] = np.asarray(
-                            jax.device_get(grp["attn"]["wo"])).mean(axis=0)
-            return out
+                        out["attn_out"] = mean_of(grp["attn"]["wo"])
+            return out, copied
 
         plan = None
         for it in range(start_step, steps):
-            chis = plane.chis(it)
-            plan_arrays = None
-            report = None
-            plan = None
-            step_fn = step_jit
-            if controller is not None:
-                if force_gamma is not None:
-                    # Figs. 5/6: force a uniform γ on EVERY rank
-                    from repro.core.workload import (PlanDynamic,
-                                                     bucket_for_gamma)
-                    b = bucket_for_gamma(force_gamma, control_cfg.gamma_buckets)
-                    plan = WorkloadPlan(
-                        plane.static,
-                        PlanDynamic(
-                            bucket_by_rank=np.full((tp,), b, np.int32),
-                            mig_src=np.array(-1, np.int32),
-                            pri_lists=controller.pri_lists()))
-                    report = None
+            with span("repro.train.step", step=it):
+                chis = plane.chis(it)
+                plan_arrays = None
+                report = None
+                plan = None
+                step_fn = step_jit
+                if controller is not None:
+                    if force_gamma is not None:
+                        # Figs. 5/6: force a uniform γ on EVERY rank
+                        from repro.core.workload import (PlanDynamic,
+                                                         bucket_for_gamma)
+                        b = bucket_for_gamma(force_gamma,
+                                             control_cfg.gamma_buckets)
+                        plan = WorkloadPlan(
+                            plane.static,
+                            PlanDynamic(
+                                bucket_by_rank=np.full((tp,), b, np.int32),
+                                mig_src=np.array(-1, np.int32),
+                                pri_lists=controller.pri_lists()))
+                        report = None
+                    else:
+                        # the controller consumes FULL-workload-equivalent
+                        # times — from the χ-oracle, or (measured mode) the
+                        # estimator's reconstruction of measured (mitigated)
+                        # times of previous steps (Eq. 1 measures the
+                        # heterogeneity degree, not the mitigated runtime)
+                        times = plane.controller_times(chis)
+                        plan, report = plane.decide(times)
+                    # pick the executable for this plan's signature and
+                    # assemble the dynamic plan arrays (projection is the
+                    # identity: the trainer simulates at real-mesh scale)
+                    step_fn, plan_arrays, _ = plane.dispatch(plan)
+                    work_frac = plane.work_frac(plan)
+
+                with span("repro.train.batch"):
+                    b = make_batch()
+                    batches_drawn += 1
+                    b = {k: jnp.asarray(v) for k, v in b.items()}
+                plane.timer.start()
+                if plan_arrays is not None:
+                    params, opt, metrics = step_fn(params, opt, b, plan_arrays)
                 else:
-                    # the controller consumes FULL-workload-equivalent
-                    # times — from the χ-oracle, or (measured mode) the
-                    # estimator's reconstruction of measured (mitigated)
-                    # times of previous steps (Eq. 1 measures the
-                    # heterogeneity degree, not the mitigated runtime)
-                    times = plane.controller_times(chis)
-                    plan, report = plane.decide(times)
-                # pick the executable for this plan's signature and
-                # assemble the dynamic plan arrays (projection is the
-                # identity here: the trainer simulates at real-mesh scale)
-                step_fn, plan_arrays, _ = plane.dispatch(plan)
-                work_frac = plane.work_frac(plan)
+                    params, opt, metrics = step_fn(params, opt, b)
+                wall = plane.timer.stop(metrics)
+                with span("repro.train.fetch"):
+                    metrics = jax.device_get(metrics)
 
-            b = make_batch()
-            batches_drawn += 1
-            b = {k: jnp.asarray(v) for k, v in b.items()}
-            plane.timer.start()
-            if plan_arrays is not None:
-                params, opt, metrics = step_fn(params, opt, b, plan_arrays)
-            else:
-                params, opt, metrics = step_fn(params, opt, b)
-            wall = plane.timer.stop(metrics)
-            metrics = jax.device_get(metrics)
+                # modeled bulk-synchronous step time (the paper's RT metric)
+                modeled = it_model.step_time(chis, work_frac)
 
-            # modeled bulk-synchronous step time (the paper's RT metric)
-            modeled = it_model.step_time(chis, work_frac)
+                # -- measurement: what a real cluster would observe THIS
+                # step — per-rank times under the ACTIVE plan (mitigated),
+                # gathered across ranks once per control interval; feeds
+                # the estimator and the trace
+                plane.capture(chis, work_frac, step=it, plan=plan, wall=wall)
 
-            # -- measurement: what a real cluster would observe THIS step —
-            # per-rank times under the ACTIVE plan (mitigated), gathered
-            # across ranks once per control interval; feeds the estimator
-            # and the trace
-            plane.capture(chis, work_frac, step=it, plan=plan, wall=wall)
+                history["loss"].append(float(metrics["loss"]))
+                history["modeled_step_s"].append(modeled)
+                history["wall_s"].append(wall)
+                if report is not None:
+                    history["gammas"].append(
+                        {int(k): float(v) for k, v in report.gammas.items()})
+                    history["mig"].append(int(report.mig_src))
+                    history["mig_shed"].append(
+                        [list(map(int, report.mig_srcs)),
+                         list(map(int, report.mig_shed))])
+                    history["buckets"].append(
+                        [int(x) for x in report.bucket_by_rank])
+                    history["signatures"].append(
+                        plan.static.signature_str())
 
-            history["loss"].append(float(metrics["loss"]))
-            history["modeled_step_s"].append(modeled)
-            history["wall_s"].append(wall)
-            if report is not None:
-                history["gammas"].append(
-                    {int(k): float(v) for k, v in report.gammas.items()})
-                history["mig"].append(int(report.mig_src))
-                history["mig_shed"].append(
-                    [list(map(int, report.mig_srcs)),
-                     list(map(int, report.mig_shed))])
-                history["buckets"].append(
-                    [int(x) for x in report.bucket_by_rank])
-                history["signatures"].append(plan.static.signature_str())
+                if controller is not None and (it + 1) % 10 == 0:
+                    with span("repro.control.weight_stats") as sp:
+                        stats, copied = scope_stats()
+                        sp.count(bytes=copied)
+                    if stats:
+                        with span("repro.control.observe_weights",
+                                  scopes=len(stats)):
+                            controller.observe_weights(stats,
+                                                       plane.wc.block_size)
 
-            if controller is not None and (it + 1) % 10 == 0:
-                stats = scope_stats()
-                if stats:
-                    controller.observe_weights(stats, plane.wc.block_size)
+                if eval_every and (it + 1) % eval_every == 0 \
+                        and cfg.num_classes:
+                    from repro.data.pipeline import eval_accuracy
+                    def predict(bb):
+                        return api.forward(params, cfg,
+                                           jnp.asarray(patchify(bb["images"])))
+                    with span("repro.train.eval"):
+                        acc = eval_accuracy(predict, eval_stream,
+                                            EVAL_BATCHES)
+                    history["acc"].append(acc)
+                    if not quiet:
+                        print(f"  step {it+1}: eval acc {acc:.3f}")
 
-            if eval_every and (it + 1) % eval_every == 0 and cfg.num_classes:
-                from repro.data.pipeline import eval_accuracy
-                def predict(bb):
-                    return api.forward(params, cfg,
-                                       jnp.asarray(patchify(bb["images"])))
-                acc = eval_accuracy(predict, eval_stream, EVAL_BATCHES)
-                history["acc"].append(acc)
-                if not quiet:
-                    print(f"  step {it+1}: eval acc {acc:.3f}")
+                if not quiet and (it + 1) % log_every == 0:
+                    print(f"step {it+1:4d} loss={metrics['loss']:.4f} "
+                          f"wall={wall*1e3:.0f}ms modeled={modeled*1e3:.1f}ms")
 
-            if not quiet and (it + 1) % log_every == 0:
-                print(f"step {it+1:4d} loss={metrics['loss']:.4f} "
-                      f"wall={wall*1e3:.0f}ms modeled={modeled*1e3:.1f}ms")
-
-            if ckpt_dir and (it + 1) % max(ckpt_every, 1) == 0 \
-                    and (it + 1) < steps:
-                save_ckpt(it + 1)
+                if ckpt_dir and (it + 1) % max(ckpt_every, 1) == 0 \
+                        and (it + 1) < steps:
+                    with span("repro.train.checkpoint"):
+                        save_ckpt(it + 1)
 
         if ckpt_dir:
-            save_ckpt(steps)
+            with span("repro.train.checkpoint"):
+                save_ckpt(steps)
         plane.close()
         history["final_loss"] = history["loss"][-1] if history["loss"] else None
         history["mean_modeled_step_s"] = float(

@@ -1,7 +1,7 @@
 """Closed-loop telemetry: measured per-rank timing, online straggler
 estimation, and record/replay traces (DESIGN_TELEMETRY.md).
 
-Three layers, consumed bottom-up by the launch drivers:
+Four modules, consumed bottom-up by the launch drivers:
 
 * :mod:`repro.telemetry.timing` — measurement. ``RankTimer`` wraps the
   jitted step with a host ``perf_counter`` around ``block_until_ready``
@@ -14,12 +14,16 @@ Three layers, consumed bottom-up by the launch drivers:
   spikes by median/MAD, gates on warmup, and serves the controller
   FULL-workload-equivalent times so the loop is not fooled by its own
   mitigation.
+* :mod:`repro.telemetry.spans` — ``span(name, **counters)``, named host
+  spans in the profiler's trace (a ``jax.profiler.TraceAnnotation``
+  with checked plain-valued counters).
 * :mod:`repro.telemetry.trace` — record/replay. Versioned JSONL
   ``TraceWriter``/``TraceReader`` for ``StepSample`` streams and
   ``schedule_from_trace`` which turns a recorded trace into a
   ``HeteroSchedule(kind="trace")`` replay.
 """
 from repro.telemetry.estimator import EstimatorConfig, StragglerEstimator
+from repro.telemetry.spans import span
 from repro.telemetry.timing import (RankTimer, StepSample, capture_sample,
                                     measurement_rng)
 from repro.telemetry.trace import (TRACE_SCHEMA, TRACE_VERSION,
@@ -29,7 +33,7 @@ from repro.telemetry.trace import (TRACE_SCHEMA, TRACE_VERSION,
 
 __all__ = [
     "EstimatorConfig", "StragglerEstimator", "RankTimer", "StepSample",
-    "capture_sample", "measurement_rng",
+    "capture_sample", "measurement_rng", "span",
     "TRACE_SCHEMA", "TRACE_VERSION", "TraceFormatError", "TraceReader",
     "TraceWriter", "replica_schedules", "schedule_from_trace",
 ]

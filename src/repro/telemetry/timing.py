@@ -28,6 +28,8 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
+from repro.telemetry.spans import span
+
 
 @dataclasses.dataclass
 class StepSample:
@@ -69,7 +71,8 @@ class RankTimer:
     """Host wall-clock + per-rank gather for the measurement loop.
 
     ``start``/``stop`` measure the real step wall (``stop`` blocks on the
-    step outputs first, so async dispatch cannot hide device time).
+    step outputs first, so async dispatch cannot hide device time), and
+    open and close the profiler span ``repro.device`` around it.
     ``gather`` pushes a per-rank local-clock vector through the jitted
     all-gather — run every ``interval`` steps by ``maybe_gather`` so the
     collective stays off the per-iteration critical path.
@@ -81,10 +84,13 @@ class RankTimer:
         self.interval = max(int(interval), 1)
         self._gather_fn = None
         self._t0: Optional[float] = None
+        self._span = None
         self.gather_count = 0
 
     # -- host wall ---------------------------------------------------------
     def start(self) -> None:
+        self._span = span("repro.device")
+        self._span.__enter__()
         self._t0 = time.perf_counter()
 
     def stop(self, outputs=None) -> float:
@@ -94,7 +100,11 @@ class RankTimer:
             jax.block_until_ready(outputs)
         t0 = self._t0 if self._t0 is not None else time.perf_counter()
         self._t0 = None
-        return time.perf_counter() - t0
+        wall = time.perf_counter() - t0
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+        return wall
 
     # -- per-rank gather ----------------------------------------------------
     def _gather(self):
