@@ -25,9 +25,9 @@ import numpy as np
 
 from repro.config import WorkloadControlConfig
 from repro.core.hetero import IterationModel
-from repro.core.priority import (PriorityState, build_pri_list,
-                                 differentiated_gamma, mark_pruned,
-                                 update_state)
+from repro.core.priority import (PriorityState, apply_variation,
+                                 build_pri_list, differentiated_gamma,
+                                 mark_pruned)
 from repro.core.workload import (PlanDynamic, PlanStatic, WorkloadPlan,
                                  bucket_for_gamma, keep_blocks_for_bucket,
                                  quantize_shed, shed_bucket_counts)
@@ -217,12 +217,15 @@ class SemiController:
         self._t_at_refresh: Optional[np.ndarray] = None
 
     # -- priority bookkeeping -------------------------------------------
-    def observe_weights(self, named_weights: Dict[str, np.ndarray], block: int):
-        """Epoch-granularity statistics refresh (Alg. 1)."""
-        for name, w in named_weights.items():
-            nb = w.shape[0] // block
-            st = self.priority.get(name) or PriorityState.create(nb)
-            self.priority[name] = update_state(st, np.asarray(w), block)
+    def observe_variation(self, fresh: Dict[str, np.ndarray],
+                          snapshots: Dict[str, object]):
+        """Epoch-granularity statistics refresh (Alg. 1) from each scope's
+        fresh ``[nb]`` variation and its advanced snapshot, both measured
+        next to the weights (``launch/train.py`` ``WeightStats``)."""
+        for name, var in fresh.items():
+            st = (self.priority.get(name)
+                  or PriorityState.create(int(var.shape[0])))
+            self.priority[name] = apply_variation(st, var, snapshots[name])
 
     def pri_lists(self) -> Dict[str, np.ndarray]:
         return {name: build_pri_list(st, self.rng, self.cfg.selection

@@ -36,7 +36,9 @@ class PriorityState:
     num_blocks: int
     w_var: np.ndarray                    # [nb] mean |Δw| per block
     pruned_last: np.ndarray              # [nb] bool: pruned in last window
-    snapshot: Optional[np.ndarray] = None  # weight values at last update
+    # mean-over-layers weight at the last update (numpy, or the device
+    # array the trainer keeps it in)
+    snapshot: Optional[np.ndarray] = None
 
     @staticmethod
     def create(num_blocks: int) -> "PriorityState":
@@ -77,6 +79,20 @@ def update_state(state: PriorityState, w_new: np.ndarray, block: int) -> Priorit
     shape = (K,) + (1,) * (w_new.ndim - 1)
     snap = np.where(keep_rows.reshape(shape), state.snapshot, w_new)
     return dataclasses.replace(state, w_var=w_var, snapshot=snap)
+
+
+def apply_variation(state: PriorityState, fresh: np.ndarray,
+                    snapshot) -> PriorityState:
+    """``update_state``'s bookkeeping, given the fresh per-block variation
+    and the advanced snapshot computed elsewhere (next to the weights):
+    the first refresh stores the snapshot and marks every block unseen;
+    later ones keep the statistic of blocks pruned in the last window."""
+    if state.snapshot is None:
+        w_var = np.full((state.num_blocks,), np.inf, np.float64)
+    else:
+        w_var = np.where(state.pruned_last, state.w_var,
+                         np.asarray(fresh, np.float64))
+    return dataclasses.replace(state, w_var=w_var, snapshot=snapshot)
 
 
 def build_pri_list(state: PriorityState, rng: Optional[np.random.Generator] = None,

@@ -35,6 +35,7 @@ ensure_host_devices(_argv_int("--tp") * _argv_int("--dp"))
 
 import argparse
 import dataclasses
+import functools
 import json
 import os
 from typing import Dict, Optional, Union
@@ -43,6 +44,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import store as ckpt_store
 from repro.config import (ModelConfig, ShapeConfig, TrainConfig,
@@ -113,6 +115,99 @@ def _resolve_geometry(spec: Optional[str], cfg, tp: int, *, hetero_kind: str,
         sizes = geom_lib.parse_geometry_arg(str(spec), tp)
         geo = geom_lib.geometry_for_cfg(cfg, sizes, TRAIN_BLOCK)
     return None if geo.is_equal else geo
+
+
+def stat_weights(params, scopes) -> Dict[str, jax.Array]:
+    """The layer-stacked weight each controlled scope's statistics are
+    taken over: ffn -> w_down [L, d_ff, d]; qkv -> wq [L, d, H*hd];
+    attn_out -> wo [L, H*hd, d] (contraction axis after the layer axis
+    in every case)."""
+    st = params["stack"] if "stack" in params else params.get("decoder", {})
+    scan = st.get("scan") if isinstance(st, dict) else None
+    if scan is None:
+        return {}
+    out = {}
+    for grp in (scan if isinstance(scan, tuple) else (scan,)):
+        if not isinstance(grp, dict):
+            continue
+        if "ffn" in grp and "ffn" in scopes:
+            out.setdefault("ffn", grp["ffn"]["w_down"])
+        attn = grp.get("attn")
+        if isinstance(attn, dict):
+            if "qkv" in scopes and "wq" in attn:
+                out.setdefault("qkv", attn["wq"])
+            if "attn_out" in scopes and "wo" in attn:
+                out.setdefault("attn_out", attn["wo"])
+    return out
+
+
+def _refresh(weights, snapshots, pruned_last, *, block: int):
+    """Alg. 1's statistics refresh for each scope, on the device: the
+    float32 mean over layers, its per-block mean |Δw| along the
+    contraction axis against the snapshot (``block_variation``), and the
+    snapshot advanced on the rows of blocks not pruned last window
+    (``update_state``)."""
+    fresh, advanced = {}, {}
+    for name, w in weights.items():
+        mean = w.astype(jnp.float32).mean(axis=0)
+        snap = snapshots[name]
+        per_row = jnp.abs(mean - snap).mean(axis=tuple(range(1, mean.ndim)))
+        fresh[name] = per_row.reshape(-1, block).mean(axis=1)
+        keep = jnp.repeat(pruned_last[name], block).reshape(
+            (-1,) + (1,) * (mean.ndim - 1))
+        advanced[name] = jnp.where(keep, snap, mean)
+    return fresh, advanced
+
+
+class WeightStats:
+    """The controller's weight statistics, computed next to the weights.
+
+    One executable, compiled here from the weights' shapes and shardings
+    so that nothing compiles when it runs, takes each scope's layer-stacked
+    weight, its snapshot and its ``[nb]`` pruned-last mask (``_refresh``).
+    Only the ``[nb]`` variations come to the host; the snapshots stay on
+    the device, each sharded as its mean over layers. A scope with no
+    snapshot yet is measured against zeros, which the controller discards.
+    """
+
+    def __init__(self, weights: Dict[str, jax.Array], block: int):
+        mesh = next(iter(weights.values())).sharding.mesh
+        self.snap_sh = {k: NamedSharding(mesh, P(*tuple(w.sharding.spec)[1:]))
+                        for k, w in weights.items()}
+        self.nb = {k: w.shape[1] // block for k, w in weights.items()}
+        self.blocks = sum(self.nb.values())
+        self.nbytes = 4 * self.blocks          # float32 variations
+        rep = NamedSharding(mesh, P())
+        fn = jax.jit(functools.partial(_refresh, block=block),
+                     in_shardings=({k: w.sharding for k, w in weights.items()},
+                                   self.snap_sh, {k: rep for k in weights}),
+                     out_shardings=({k: rep for k in weights}, self.snap_sh))
+        snaps = {k: jax.ShapeDtypeStruct(w.shape[1:], jnp.float32)
+                 for k, w in weights.items()}
+        self.refresh = fn.lower(
+            {k: jax.ShapeDtypeStruct(w.shape, w.dtype)
+             for k, w in weights.items()}, snaps,
+            {k: jax.ShapeDtypeStruct((n,), jnp.bool_)
+             for k, n in self.nb.items()}).compile()
+        self.zeros = {k: jax.device_put(np.zeros(s.shape, s.dtype),
+                                        self.snap_sh[k])
+                      for k, s in snaps.items()}
+
+    def __call__(self, weights, priority):
+        """Fresh ``[nb]`` variation per scope (numpy, fetched in the span
+        ``repro.control.weight_stats.fetch``) and the advanced snapshots
+        (device arrays), given the controller's priority states."""
+        snaps, masks = {}, {}
+        for k, n in self.nb.items():
+            st = priority.get(k)
+            have = st is not None and st.snapshot is not None
+            snaps[k] = (jax.device_put(st.snapshot, self.snap_sh[k])
+                        if have else self.zeros[k])
+            masks[k] = st.pruned_last if have else np.zeros((n,), bool)
+        fresh, advanced = self.refresh(weights, snaps, masks)
+        with span("repro.control.weight_stats.fetch", bytes=self.nbytes):
+            fresh = jax.device_get(fresh)
+        return fresh, advanced
 
 
 def run_training(arch: Union[str, ModelConfig], *, steps: int = 50,
@@ -270,6 +365,12 @@ def run_training(arch: Union[str, ModelConfig], *, steps: int = 50,
                     start_step = last
                     batches_drawn = last
 
+        # the statistics refresh compiles here, with the plane's steps,
+        # so none compiles inside the loop
+        stat_like = stat_weights(params, scopes)
+        weight_stats = (WeightStats(stat_like, plane.wc.block_size)
+                        if controller is not None and stat_like else None)
+
         def save_ckpt(step_now: int) -> None:
             tree = {"params": params, "opt": opt}
             plane_arrays = plane.state_arrays()
@@ -319,41 +420,6 @@ def run_training(arch: Union[str, ModelConfig], *, steps: int = 50,
         history = {"loss": [], "acc": [], "modeled_step_s": [],
                    "gammas": [], "mig": [], "mig_shed": [],
                    "buckets": [], "signatures": [], "wall_s": []}
-
-        def scope_stats():
-            """Mean-over-layers weight matrices per controlled scope:
-            ffn -> w_down [d_ff, d]; qkv -> wq [d, H*hd]; attn_out ->
-            wo [H*hd, d] (contraction dim first in every case).
-
-            Returns the statistics and the bytes copied to the host; each
-            copy is the profiler span ``repro.control.weight_stats.fetch``
-            so the caller's span keeps the mean as its own time."""
-            st = params["stack"] if "stack" in params else params.get("decoder", {})
-            scan = st.get("scan") if isinstance(st, dict) else None
-            if scan is None:
-                return {}, 0
-            out = {}
-            copied = 0
-
-            def mean_of(a):
-                nonlocal copied
-                with span("repro.control.weight_stats.fetch",
-                          bytes=int(a.nbytes)):
-                    host = np.asarray(jax.device_get(a))
-                copied += int(a.nbytes)
-                return host.mean(axis=0)
-
-            for grp in (scan if isinstance(scan, tuple) else (scan,)):
-                if not isinstance(grp, dict):
-                    continue
-                if "ffn" in grp and "ffn" in scopes and "ffn" not in out:
-                    out["ffn"] = mean_of(grp["ffn"]["w_down"])
-                if "attn" in grp and isinstance(grp["attn"], dict):
-                    if "qkv" in scopes and "wq" in grp["attn"] and "qkv" not in out:
-                        out["qkv"] = mean_of(grp["attn"]["wq"])
-                    if "attn_out" in scopes and "wo" in grp["attn"]                             and "attn_out" not in out:
-                        out["attn_out"] = mean_of(grp["attn"]["wo"])
-            return out, copied
 
         plan = None
         for it in range(start_step, steps):
@@ -428,15 +494,16 @@ def run_training(arch: Union[str, ModelConfig], *, steps: int = 50,
                     history["signatures"].append(
                         plan.static.signature_str())
 
-                if controller is not None and (it + 1) % 10 == 0:
-                    with span("repro.control.weight_stats") as sp:
-                        stats, copied = scope_stats()
-                        sp.count(bytes=copied)
-                    if stats:
-                        with span("repro.control.observe_weights",
-                                  scopes=len(stats)):
-                            controller.observe_weights(stats,
-                                                       plane.wc.block_size)
+                if weight_stats is not None and (it + 1) % 10 == 0:
+                    with span("repro.control.weight_stats",
+                              bytes=weight_stats.nbytes,
+                              blocks=weight_stats.blocks):
+                        fresh, snaps = weight_stats(
+                            stat_weights(params, scopes),
+                            controller.priority)
+                    with span("repro.control.observe_weights",
+                              scopes=len(fresh)):
+                        controller.observe_variation(fresh, snaps)
 
                 if eval_every and (it + 1) % eval_every == 0 \
                         and cfg.num_classes:

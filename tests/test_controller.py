@@ -1,4 +1,6 @@
 """Controller (Eqs. 1-3, Alg. 2) and priority (Alg. 1) tests."""
+import os
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -10,6 +12,8 @@ from repro.core.controller import (CostFunctions, SemiController, eq1_gamma,
                                    eq2_beta, eq3_migration_prefix,
                                    work_fraction)
 from repro.core.workload import keep_blocks_for_bucket
+
+from test_multidevice import run_py
 
 
 COSTS = CostFunctions(omega1=1e-3, omega2_slope=1e-5, phi1_base=5e-5,
@@ -108,6 +112,153 @@ class TestPriority:
         out = pri_lib.differentiated_gamma(states, 0.5, alpha=0.8,
                                            theta=1e-3, buckets=buckets)
         assert buckets[out["a"]] >= 0.8 * 0.5 - 1e-9
+
+
+def stat_fixture(shapes, block, seed, refreshes=3):
+    """Layer-stacked weights [L, K, N] per scope for each refresh.
+
+    Every value is a multiple of 2**-14 well inside float32's mantissa,
+    and ``L`` is a power of two, so the mean over layers is exact in any
+    summation order (XLA divides by ``L`` as a product with its rounded
+    reciprocal) and the device and numpy snapshots can be compared bit
+    for bit. Each block moves by
+    its own step (1/64 apart) plus small per-element noise, so no two
+    blocks' statistics lie within 1e-5 of each other."""
+    rng = np.random.default_rng(seed)
+    out = [{} for _ in range(refreshes)]
+    for name, (L, K, N) in shapes.items():
+        nb = K // block
+        w = rng.integers(-512, 513, (L, K, N)) / 1024
+        step = np.repeat(rng.permutation(nb) + 1.0, block)[:, None] / 64
+        for r in range(refreshes):
+            sign = rng.choice([-1.0, 1.0], (K, N))
+            w = w + step * sign + rng.integers(-4, 5, (L, K, N)) / 4096
+            out[r][name] = w.astype(np.float32)
+    return out
+
+
+def check_device_refresh(shapes, shardings, block, seed=0):
+    """Three refreshes through ``WeightStats`` and through the numpy
+    ``update_state``, with half of every scope's blocks pruned after
+    each: the statistics agree to 1e-5, the keep-first orders are the
+    same and the snapshots are bit-identical, on the rows each path
+    keeps and on those it advances."""
+    import jax
+    from repro.launch.train import WeightStats
+    steps = stat_fixture(shapes, block, seed)
+    put = lambda ws: {k: jax.device_put(w, shardings[k])
+                      for k, w in ws.items()}
+    stats = WeightStats(put(steps[0]), block)
+    dev, ref = {}, {}
+    kept_rows = 0
+    for ws in steps:
+        fresh, snaps = stats(put(ws), dev)
+        for k, w in ws.items():
+            nb = w.shape[1] // block
+            st = dev.get(k) or pri_lib.PriorityState.create(nb)
+            kept_rows += int(st.pruned_last.sum()) * block
+            dev[k] = pri_lib.apply_variation(st, fresh[k], snaps[k])
+            ref[k] = pri_lib.update_state(
+                ref.get(k) or pri_lib.PriorityState.create(nb),
+                w.mean(axis=0), block)
+            np.testing.assert_allclose(dev[k].w_var, ref[k].w_var,
+                                       rtol=1e-5)
+            np.testing.assert_array_equal(np.asarray(dev[k].snapshot),
+                                          ref[k].snapshot)
+            finite = np.sort(ref[k].w_var[np.isfinite(ref[k].w_var)])
+            assert np.all(np.diff(finite) > 1e-5 * finite[1:])
+            pri = pri_lib.build_pri_list(ref[k])
+            np.testing.assert_array_equal(pri_lib.build_pri_list(dev[k]),
+                                          pri)
+            dev[k] = pri_lib.mark_pruned(dev[k], pri, nb // 2)
+            ref[k] = pri_lib.mark_pruned(ref[k], pri, nb // 2)
+    assert kept_rows > 0
+
+
+@pytest.mark.parametrize("block", [8, 128])
+def test_device_refresh_matches_update_state(block):
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_small_mesh
+    shapes = {"ffn": (4, 4 * block, 24), "qkv": (4, 2 * block, 24),
+              "attn_out": (4, 2 * block, 16)}
+    one = NamedSharding(make_small_mesh(1, 1), P())
+    check_device_refresh(shapes, {k: one for k in shapes}, block)
+
+
+def test_device_refresh_matches_update_state_tp4():
+    """The same at four host devices, on the trainer's shardings."""
+    run_py(f"""
+import sys
+sys.path.insert(0, {os.path.dirname(os.path.abspath(__file__))!r})
+import jax, jax.numpy as jnp
+from repro.config import ShapeConfig, TrainConfig, resolve_model
+from repro.launch import steps
+from repro.launch.mesh import make_small_mesh
+from repro.launch.train import stat_weights
+from repro.models import get_api
+from repro.sharding import use_mesh
+from test_controller import check_device_refresh
+cfg = resolve_model("vit-1b")
+mesh = make_small_mesh(1, 4)
+with use_mesh(mesh):
+    _, _, in_sh, _ = steps.build_train_step(
+        cfg, ShapeConfig("trainer", 64, 4, "train"), mesh, TrainConfig())
+    scopes = ("ffn", "qkv", "attn_out")
+    shapes = stat_weights(jax.eval_shape(lambda: get_api(cfg).init(
+        jax.random.PRNGKey(0), cfg, jnp.float32)[0]), scopes)
+    shardings = stat_weights(in_sh[0], scopes)
+    assert set(shapes) == set(scopes)
+    assert any("model" in str(s.spec) for s in shardings.values())
+    check_device_refresh({{k: v.shape for k, v in shapes.items()}},
+                         shardings, 8)
+print("ok")
+""", devices=4)
+
+
+def test_resume_after_device_refresh():
+    """A controller restored from ``state_arrays`` taken after a device
+    refresh measures the next refresh as the uninterrupted one does."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_small_mesh
+    from repro.launch.train import WeightStats
+    block = 8
+    one = NamedSharding(make_small_mesh(1, 1), P())
+    steps = [{k: jax.device_put(w, one) for k, w in ws.items()}
+             for ws in stat_fixture({"ffn": (4, 64, 24), "qkv": (4, 32, 24)},
+                                    block, seed=1)]
+    stats = WeightStats(steps[0], block)
+    cfg = WorkloadControlConfig(enabled=True, mode="zero", block_size=block)
+    model = hetero_lib.IterationModel(matmul_time=1.0, other_time=0.1)
+
+    def controller():
+        return SemiController(cfg, 4, model, num_blocks=8)
+
+    def refresh(c, ws):
+        c.observe_variation(*stats(ws, c.priority))
+
+    times = np.array([2.0, 1.0, 1.0, 1.0])
+    a = controller()
+    for ws in steps[:2]:
+        refresh(a, ws)
+        a.plan(times)
+    assert any(st.pruned_last.any() for st in a.priority.values())
+    b = controller()
+    b.load_state_arrays(a.state_arrays())
+    assert all(isinstance(st.snapshot, np.ndarray)
+               for st in b.priority.values())
+    refresh(a, steps[2])
+    refresh(b, steps[2])
+    for k in a.priority:
+        np.testing.assert_array_equal(b.priority[k].w_var,
+                                      a.priority[k].w_var)
+        assert np.isfinite(a.priority[k].w_var).all()
+    want = a.pri_lists()
+    got = b.pri_lists()
+    assert set(got) == set(want)
+    for k in want:
+        np.testing.assert_array_equal(got[k], want[k])
 
 
 class TestSemiController:

@@ -142,11 +142,32 @@ def test_serve_engine_spans(tmp_path):
     assert finished == {u: len(done[u].tokens) for u in prompts}
 
 
+COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                  "/jax/core/compile/backend_compile_duration")
+
+
+@contextlib.contextmanager
+def compile_marks():
+    """Each compile event ``jax.monitoring`` reports, as a span
+    ``repro.test.compile`` on the trace's clock."""
+    def mark(name, secs, **_):
+        if name in COMPILE_EVENTS:
+            with span("repro.test.compile"):
+                pass
+    jax.monitoring.register_event_duration_secs_listener(mark)
+    try:
+        yield
+    finally:
+        jax.monitoring.unregister_event_duration_listener(mark)
+
+
 def test_train_loop_spans(tmp_path):
     from repro.config import resolve_model
-    from repro.launch.train import run_training
+    from repro.control.scopes import plan_block_size
+    from repro.launch.train import TRAIN_BLOCK, run_training
     from repro.models import get_api
-    with traced(tmp_path) as spans:
+    with traced(tmp_path) as spans, compile_marks():
         hist = run_training("vit-1b", steps=10, tp=1, batch=2, quiet=True,
                             control_mode="semi", hetero_kind="static",
                             chi=2.0)
@@ -166,23 +187,31 @@ def test_train_loop_spans(tmp_path):
     assert sum(s.args["new_signature"] for s in dispatch) \
         == hist["plan_compiles"] - 1 == 0
 
-    # the weight statistics, once, on the tenth step
+    # the weight statistics, once, on the tenth step: only each scope's
+    # [nb] float32 variation comes to the host, in one fetch
     stats, = [s for s in spans if s.name == "repro.control.weight_stats"]
     assert stats.parent == "repro.train.step"
-    fetches = [s for s in spans
-               if s.name == "repro.control.weight_stats.fetch"]
-    assert all(s.parent == "repro.control.weight_stats" for s in fetches)
+    assert children(spans, steps[-1]).count(stats) == 1
+    fetch, = [s for s in spans
+              if s.name == "repro.control.weight_stats.fetch"]
+    assert fetch.parent == "repro.control.weight_stats"
     cfg = resolve_model("vit-1b")
+    block = plan_block_size(cfg, TRAIN_BLOCK, 1, False)
     shapes = jax.eval_shape(lambda: get_api(cfg).init(
         jax.random.PRNGKey(0), cfg, jnp.float32)[0])
     want = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(shapes)[0]:
         keys = tuple(str(getattr(k, "key", k)) for k in path)
         if keys[-2:] in (("ffn", "w_down"), ("attn", "wq"), ("attn", "wo")):
-            want[keys[-2:]] = int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+            want[keys[-2:]] = leaf.shape[1] // block
     assert len(want) == 3
-    assert sorted(s.args["bytes"] for s in fetches) == sorted(want.values())
-    assert stats.args["bytes"] == sum(want.values())
+    assert fetch.args["bytes"] == stats.args["bytes"] \
+        == 4 * sum(want.values())
+    assert stats.args["blocks"] == sum(want.values())
+    # the refresh was compiled before the loop
+    marks = [s for s in spans if s.name == "repro.test.compile"]
+    assert marks and not [m for m in marks
+                          if stats.start <= m.start <= stats.end]
     observe, = [s for s in spans if s.name == "repro.control.observe_weights"]
     assert observe.args["scopes"] == 3 and observe.start >= stats.end
 
